@@ -15,23 +15,19 @@ runs use independent per-shard propagation noise and must agree
 statistically.  The final row reports that check: the 4-shard LAZY_SR
 log-likelihood vs. the single-device estimate.
 
+On a CPU host the mesh is faked: :func:`use_host_devices` gives the CPU
+backend four devices, and must run before anything initializes JAX's
+backends (``benchmarks/run.py`` calls it first thing).  On an
+accelerator host the mesh spans the real chips, in the same process.
+
 Run:  PYTHONPATH=src python benchmarks/bench_sharded.py
-(or through ``benchmarks/run.py --only sharded``; note this module must
-be imported before anything initializes jax, because the device-count
-flag only takes effect at first initialization).
+(or through ``python -m benchmarks.run --only sharded``).
 """
 
 from __future__ import annotations
 
-import os
-
-_FLAGS = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _FLAGS:
-    os.environ["XLA_FLAGS"] = (
-        _FLAGS + " --xla_force_host_platform_device_count=4"
-    ).strip()
-
 import math
+import os
 import time
 
 import jax
@@ -48,10 +44,18 @@ if __package__ in (None, ""):  # invoked as a file path (the documented usage)
 
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
-from benchmarks.common import emit
-
 A, Q, R = 0.9, 0.5, 0.3
-KEY = jax.random.PRNGKey(0)
+
+
+def use_host_devices(n: int = 4) -> None:
+    """Fake ``n`` devices on the CPU backend, unless ``JAX_PLATFORMS``
+    names only accelerator platforms (then the CPU client is never the
+    backend and is left alone).  JAX refuses the setting once its
+    backends have started, so callers run this before any computation."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "cpu" not in platforms.split(","):
+        return
+    jax.config.update("jax_num_cpu_devices", n)
 
 
 def lgssm_def() -> SSMDef:
@@ -79,9 +83,14 @@ def _time(fn, key, obs, reps: int) -> tuple[float, object]:
 
 
 def run(n: int = 256, t: int = 48, reps: int = 3, tol: float = 3.0):
+    # Imported here: benchmarks.common computes at import, which would
+    # start JAX's backends before use_host_devices could shape them.
+    from benchmarks.common import emit
+
+    key = jax.random.PRNGKey(0)
     devices = jax.devices()
     max_shards = len(devices)
-    obs = jax.random.normal(KEY, (t,))
+    obs = jax.random.normal(key, (t,))
     rows = []
 
     # single-device reference (no mesh at all)
@@ -89,7 +98,7 @@ def run(n: int = 256, t: int = 48, reps: int = 3, tol: float = 3.0):
         lgssm_def(),
         FilterConfig(n_particles=n, n_steps=t, mode=CopyMode.LAZY_SR, block_size=2),
     )
-    secs0, res0 = _time(pf0.jitted(), KEY, obs, reps)
+    secs0, res0 = _time(pf0.jitted(), key, obs, reps)
     ref_logz = float(res0.log_evidence)
     rows.append(
         emit(
@@ -113,7 +122,7 @@ def run(n: int = 256, t: int = 48, reps: int = 3, tol: float = 3.0):
                     n_particles=n, n_steps=t, mode=mode, block_size=2, mesh=mesh
                 ),
             )
-            secs, res = _time(pf.jitted(), KEY, obs, reps)
+            secs, res = _time(pf.jitted(), key, obs, reps)
             shcfg = pf.sharded_cfg
             used = np.asarray(sharded_lib.used_blocks_per_shard(shcfg, res.store))
             peak = np.asarray(sharded_lib.peak_blocks_per_shard(shcfg, res.store))
@@ -161,6 +170,7 @@ if __name__ == "__main__":
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--json", default="")
     args = ap.parse_args()
+    use_host_devices()
     if args.json:
         from benchmarks import common
 

@@ -57,6 +57,15 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    # Both must precede the first JAX computation (benchmarks.common
+    # makes one at import): the device count only shapes a backend that
+    # has not started, and the cache applies from the first compile.
+    from benchmarks import bench_sharded
+    from repro import compile_cache
+
+    if only is None or "sharded" in only:
+        bench_sharded.use_host_devices()
+    compile_cache.enable()
     from benchmarks import common
 
     if args.json:
@@ -136,24 +145,9 @@ def _run_suites(args, only, n: int, t: int) -> None:
             steps=12 if args.quick else 16,
         )
     if only is None or "sharded" in only:
-        # Subprocess: bench_sharded fakes a multi-device host via
-        # XLA_FLAGS, which must not leak into the other benchmarks'
-        # timings (same isolation idiom as the multi-device tests).  It
-        # writes its own BENCH_sharded.json when --json is set.
-        import pathlib
-        import subprocess
-        import sys
+        from benchmarks import bench_sharded
 
-        cmd = [
-            sys.executable,
-            str(pathlib.Path(__file__).resolve().parent / "bench_sharded.py"),
-            f"--n={n * 2}",
-            f"--t={t}",
-            f"--reps={2 if args.quick else 3}",
-        ]
-        if args.json:
-            cmd.append(f"--json={args.json}")
-        subprocess.run(cmd, check=True)
+        bench_sharded.run(n=n * 2, t=t, reps=2 if args.quick else 3)
 
 
 if __name__ == "__main__":

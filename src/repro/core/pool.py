@@ -68,6 +68,7 @@ from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = [
     "BlockPool",
@@ -96,7 +97,9 @@ __all__ = [
     "NULL_BLOCK",
 ]
 
-NULL_BLOCK = jnp.int32(-1)
+# A NumPy scalar, not a jnp one: same int32 promotion everywhere, and
+# importing the pool does not start JAX's backends.
+NULL_BLOCK = np.int32(-1)
 
 
 class BlockPool(NamedTuple):

@@ -32,7 +32,7 @@ Two API layers:
 
 * *inside-shard_map* primitives (:func:`sharded_clone`,
   :func:`gather_global`) for code that already runs under
-  ``jax.experimental.shard_map`` — the sharded particle filter's scan
+  ``jax.shard_map`` — the sharded particle filter's scan
   (:mod:`repro.smc.filters`) uses these directly so the whole filter
   stays one jitted program;
 * *stacked* wrappers (:func:`create`, :func:`append`, :func:`clone`,
@@ -66,7 +66,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import pool as pool_lib
@@ -351,7 +351,7 @@ def _wrapped(op: str, cfg: ShardedStoreConfig, mesh: Mesh):
     fn, in_specs, out_specs = fns[op]
     return jax.jit(
         shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
         )
     )
 
@@ -403,7 +403,7 @@ def _wrapped_grow(cfg: ShardedStoreConfig, mesh: Mesh, new_num_blocks: int):
         return restack(st._replace(pool=pool_lib.grow(st.pool, new_num_blocks)))
 
     return jax.jit(
-        shard_map(fn, mesh=mesh, in_specs=(sp,), out_specs=sp, check_rep=False)
+        shard_map(fn, mesh=mesh, in_specs=(sp,), out_specs=sp, check_vma=False)
     )
 
 
@@ -417,7 +417,7 @@ def _wrapped_compact(
         return restack(store_lib.compact(cfg.local, unstack(st), new_num_blocks))
 
     return jax.jit(
-        shard_map(fn, mesh=mesh, in_specs=(sp,), out_specs=sp, check_rep=False)
+        shard_map(fn, mesh=mesh, in_specs=(sp,), out_specs=sp, check_vma=False)
     )
 
 

@@ -1,26 +1,33 @@
 """Pallas kernel: fused resample -> table gather -> clone bookkeeping.
 
 A resampling step of the lazy-copy platform is three dispatches over the
-same small tables today: the inverse-CDF ancestor search
+same small tables: the inverse-CDF ancestor search
 (:mod:`repro.kernels.resample`), the block-table gather
 (``tables[ancestors]``), and the refcount histogram
-(:mod:`repro.kernels.refcount_update`).  Each re-reads the tables from
-HBM.  This kernel does all three in **one pass**: per row chunk it
+(:mod:`repro.kernels.refcount_update`).  Here the first two are one
+kernel: per row chunk it
 
   * counts the systematic comb against the full weight CDF
     (``anc[j] = #{i : cum[i] < (j + u) / n}`` — exactly
-    ``searchsorted(cum, (j + u) / n, side="left")``),
-  * gathers the ancestors' table rows with a one-hot fp32 matmul
-    (exact for the small int32 block ids, including NULL = -1),
-  * accumulates the signed refcount histogram and the freeze-membership
-    mask of ``new - old`` into revisited ``[1, nb]`` outputs
-    (:mod:`repro.kernels.refcount_update`'s accumulation template).
+    ``searchsorted(cum, (j + u) / n, side="left")``; the comb positions
+    are computed by the caller with the oracle's own expression, so the
+    compare sees bit-identical operands),
+  * gathers the ancestors' table rows with one-hot matmuls on the MXU:
+    ``id + 1`` (NULL = -1 included) is split into three 8-bit digits,
+    each exact in bf16, so every product has one nonzero term and the
+    f32 accumulation is exact whatever the matmul precision — block ids
+    up to 2**24 - 2,
+
+and the histogram of ``new - old`` plus the freeze membership is the
+block-id-tiled :mod:`repro.kernels.refcount_update` kernel.  A single
+fused one-hot over ``[rows * mb, num_blocks]`` does not fit VMEM at the
+paper's population sizes (2048 x 125 entries against ~43k blocks for
+RBPF), which is why the histogram is a second, tiled pass.
 
 Grid: one step per row chunk; the CDF and the full table live in VMEM
-(population tables are KB-scale).  The chunk size adapts to the table
-width so the one-hot compare stays a bounded ``[chunk * mb, nb]`` tile.
-Padded rows gather NULL rows, so they drop out of the histogram for
-free.
+(population tables are KB- to MB-scale).  The chunk adapts to ``n`` so
+the ``[chunk, n]`` compare and one-hot stay about 1 MB.  Padded rows
+gather NULL rows, so they drop out of the histogram for free.
 """
 
 from __future__ import annotations
@@ -30,66 +37,50 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-#: target table entries (rows * width) per grid step
-_ENTRIES = 1024
+from repro.kernels.refcount_update.kernel import refcount_delta_pallas
+
+#: target elements of the per-step ``[chunk, n]`` compare / one-hot
+_ELEMS = 1 << 18
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
 def _kernel(
-    u_ref,  # [1] f32
-    cum_ref,  # [n] f32 — full CDF every step
-    tab_ref,  # [n, mb] int32 — full tables every step (gather source)
-    old_ref,  # [chunk, mb] int32 — this chunk's rows (old histogram)
-    anc_ref,  # [chunk] int32 out
+    pos_ref,  # [chunk, 1] f32 — this chunk's comb positions
+    cum_ref,  # [1, n_pad] f32 — full CDF every step (padding > 1)
+    tab_ref,  # [n_pad, mb] int32 — full tables every step (gather source)
+    anc_ref,  # [chunk, 1] int32 out
     new_ref,  # [chunk, mb] int32 out
-    delta_ref,  # [1, nb] int32 out, revisited
-    member_ref,  # [1, nb] bool out, revisited
     *,
     chunk: int,
     n: int,
-    mb: int,
-    nb: int,
 ):
     i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        delta_ref[...] = jnp.zeros_like(delta_ref)
-        member_ref[...] = jnp.zeros_like(member_ref)
-
-    u = u_ref[0]
-    rows = i * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
-    t = (rows.astype(jnp.float32) + u) / n  # [chunk, 1] comb positions
-    c = cum_ref[...].reshape(1, n)
-    cnt = jnp.sum((c < t).astype(jnp.int32), axis=1)  # [chunk]
+    cnt = jnp.sum(
+        (cum_ref[...] < pos_ref[...]).astype(jnp.int32), axis=1, keepdims=True
+    )  # [chunk, 1]
     anc = jnp.clip(cnt, 0, n - 1)
     anc_ref[...] = anc
-
-    # Gather the ancestors' table rows: one-hot fp32 matmul — exact for
-    # block ids (small ints, NULL = -1 included).
-    oh = (
-        anc[:, None] == jax.lax.broadcasted_iota(jnp.int32, (chunk, n), 1)
-    ).astype(jnp.float32)
-    newt = jax.lax.dot_general(
-        oh,
-        tab_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(jnp.int32)  # [chunk, mb]
+    n_pad = cum_ref.shape[1]
+    oh = (anc == jax.lax.broadcasted_iota(jnp.int32, (chunk, n_pad), 1)).astype(
+        jnp.bfloat16
+    )
+    ids = tab_ref[...] + 1  # [n_pad, mb], >= 0
+    newt = -1
+    for shift in (0, 8, 16):
+        digit = ((ids >> shift) & 0xFF).astype(jnp.float32).astype(jnp.bfloat16)
+        part = jax.lax.dot_general(
+            oh, digit, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [chunk, mb]
+        newt = newt + (part.astype(jnp.int32) << shift)
     # Rows past n are grid padding: park them on NULL so the histogram
-    # and membership below never see them.
-    newt = jnp.where(rows < n, newt, -1)
-    new_ref[...] = newt
-
-    # Fused clone bookkeeping: signed histogram + membership of this
-    # chunk's new/old entries against the block-id lane.
-    lane = jax.lax.broadcasted_iota(jnp.int32, (chunk * mb, nb), 1)
-    new_hits = newt.reshape(chunk * mb, 1) == lane
-    old_hits = old_ref[...].reshape(chunk * mb, 1) == lane
-    delta_ref[...] += (
-        new_hits.astype(jnp.int32) - old_hits.astype(jnp.int32)
-    ).sum(axis=0, keepdims=True)
-    member_ref[...] |= new_hits.any(axis=0, keepdims=True)
+    # never sees them.
+    rows = i * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+    new_ref[...] = jnp.where(rows < n, newt, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("num_blocks", "interpret"))
@@ -103,32 +94,39 @@ def clone_chain_pallas(
 ):
     """Returns ``(ancestors [n], new_tables [n, mb], delta [nb], member [nb])``."""
     n, mb = tables.shape
-    chunk = min(max(1, _ENTRIES // max(mb, 1)), n)
-    pad = (-n) % chunk
-    steps = (n + pad) // chunk
-    old_p = jnp.pad(tables, ((0, pad), (0, 0)), constant_values=-1)
-    kernel = functools.partial(_kernel, chunk=chunk, n=n, mb=mb, nb=num_blocks)
-    anc, new_tables, delta, member = pl.pallas_call(
+    if num_blocks >= (1 << 24) - 1:
+        raise ValueError(f"clone_chain gathers block ids below 2**24 - 1; {num_blocks=}")
+    chunk = max(8, min(256, _ELEMS // _round_up(n, 128)) // 8 * 8)
+    chunk = min(chunk, _round_up(n, 8))
+    n_pad = _round_up(n, chunk)
+    # The oracle's comb expression, verbatim (see ref.py).
+    pos = (jnp.arange(n) + u[0]) / n
+    pos = jnp.pad(pos, (0, n_pad - n)).reshape(n_pad, 1)
+    cum_p = jnp.pad(cum, (0, n_pad - n), constant_values=2.0).reshape(1, n_pad)
+    tab_p = jnp.pad(tables, ((0, n_pad - n), (0, 0)), constant_values=-1)
+    kernel = functools.partial(_kernel, chunk=chunk, n=n)
+    anc, new_tables = pl.pallas_call(
         kernel,
-        grid=(steps,),
+        grid=(n_pad // chunk,),
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((n,), lambda i: (0,)),
-            pl.BlockSpec((n, mb), lambda i: (0, 0)),
-            pl.BlockSpec((chunk, mb), lambda i: (i, 0)),
+            pl.BlockSpec((chunk, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, n_pad), lambda i: (0, 0)),
+            pl.BlockSpec((n_pad, mb), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((chunk,), lambda i: (i,)),
+            pl.BlockSpec((chunk, 1), lambda i: (i, 0)),
             pl.BlockSpec((chunk, mb), lambda i: (i, 0)),
-            pl.BlockSpec((1, num_blocks), lambda i: (0, 0)),
-            pl.BlockSpec((1, num_blocks), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n + pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n + pad, mb), jnp.int32),
-            jax.ShapeDtypeStruct((1, num_blocks), jnp.int32),
-            jax.ShapeDtypeStruct((1, num_blocks), jnp.bool_),
+            jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n_pad, mb), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(u, cum, tables, old_p)
-    return anc[:n], new_tables[:n], delta[0], member[0]
+    )(pos, cum_p, tab_p)
+    new_tables = new_tables[:n]
+    delta, member = refcount_delta_pallas(
+        new_tables.reshape(-1), tables.reshape(-1),
+        num_blocks=num_blocks, interpret=interpret,
+    )
+    return anc[:n, 0], new_tables, delta, member

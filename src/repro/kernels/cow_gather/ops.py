@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from repro.kernels.cow_gather.kernel import cow_gather_pallas
 from repro.kernels.cow_gather.ref import cow_gather_ref
 from repro.kernels.dispatch import resolve_kernel_mode
+from repro.kernels.pool_rows import as_rank3
 
 
 def cow_gather(
@@ -30,10 +31,8 @@ def cow_gather(
     use_kernel, interpret = resolve_kernel_mode(use_kernel, interpret)
     if not use_kernel:
         return cow_gather_ref(pool, table)
-    shape = pool.shape
-    flat = pool.reshape(shape[0], -1)
-    out = cow_gather_pallas(flat, table, interpret=interpret)
-    return out.reshape((table.shape[0],) + shape[1:])
+    out = cow_gather_pallas(as_rank3(pool), table, interpret=interpret)
+    return out.reshape((table.shape[0],) + pool.shape[1:])
 
 
 def pool_compact(
@@ -52,7 +51,8 @@ def pool_compact(
     resize).  Returns ``[target + 1, *block_shape]`` with a fresh
     kept-zero dump row at the new ``target`` index.  One streamed gather
     pass over the live payload — the same scalar-prefetch kernel that
-    materializes trajectories.
+    materializes trajectories; the dump row is one more NULL entry, so
+    no second pass concatenates it.
     """
-    rows = cow_gather(data, perm, use_kernel=use_kernel, interpret=interpret)
-    return jnp.concatenate([rows, jnp.zeros_like(rows[:1])], axis=0)
+    perm = jnp.concatenate([perm, jnp.full((1,), -1, perm.dtype)])
+    return cow_gather(data, perm, use_kernel=use_kernel, interpret=interpret)

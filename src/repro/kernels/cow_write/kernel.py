@@ -17,6 +17,12 @@ Routing contract (established by ``store._write_impl``):
   write-only slab nothing ever reads, so skipped rows cost one
   cache-resident self-copy rather than a branch.
 
+Blocks keep the payload's native shape (see
+:mod:`repro.kernels.pool_rows`): a ``[bs, *item]`` row streams whole,
+and a row whose items are rank >= 2 streams one slot per grid step, so
+the item merge is a select along the slot axis — no flattening relayout
+of the pool, and no ``(1, block_elems)`` block that splits a tiled dim.
+
 The output aliases the pool (``input_output_aliases``), so untouched
 blocks are not rewritten.  Aliasing is race-free because no row's
 ``src`` can be another row's ``dst`` within one call: copy sources are
@@ -34,127 +40,91 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.pool_rows import row_blocking
+
+
+def _merge(pos_ref, data_ref, val_ref, keep_ref, out_ref):
+    i = pl.program_id(0)
+    block = data_ref[...]  # [1, slots, *item] — (a slice of) the source block
+    slot = pl.program_id(1) * block.shape[1] + jax.lax.broadcasted_iota(
+        jnp.int32, block.shape, 1
+    )
+    if keep_ref is not None:
+        # Delta merge: kept slots copy the source, everything else is
+        # zero-filled (the delta-COW invariant).
+        block = jnp.where(keep_ref[...] != 0, block, jnp.zeros_like(block))
+    # The written item wins at `pos`.
+    out_ref[...] = jnp.where(slot == pos_ref[i], val_ref[...], block)
+
 
 def _kernel(src_ref, dst_ref, pos_ref, data_ref, val_ref, out_ref):
     del src_ref, dst_ref  # consumed by the index maps
-    i = pl.program_id(0)
-    pos = pos_ref[i]
-    block = data_ref[...]  # [1, block_elems] — the source block
-    val = val_ref[...]  # [1, item_elems]
-    be = block.shape[1]
-    ie = val.shape[1]
-    bs = be // ie
-    # Lane j belongs to item j // ie; merge the value into item `pos`.
-    item_of_lane = jax.lax.broadcasted_iota(jnp.int32, (1, be), 1) // ie
-    val_tiled = jnp.broadcast_to(val.reshape(1, 1, ie), (1, bs, ie)).reshape(1, be)
-    out_ref[...] = jnp.where(item_of_lane == pos, val_tiled, block)
+    _merge(pos_ref, data_ref, val_ref, None, out_ref)
 
 
 def _kernel_delta(src_ref, dst_ref, pos_ref, data_ref, val_ref, keep_ref, out_ref):
     del src_ref, dst_ref  # consumed by the index maps
-    i = pl.program_id(0)
-    pos = pos_ref[i]
-    block = data_ref[...]  # [1, block_elems] — the source block
-    val = val_ref[...]  # [1, item_elems]
-    keep = keep_ref[...]  # [1, block_size] int32
-    be = block.shape[1]
-    ie = val.shape[1]
-    bs = be // ie
-    item_of_lane = jax.lax.broadcasted_iota(jnp.int32, (1, be), 1) // ie
-    val_tiled = jnp.broadcast_to(val.reshape(1, 1, ie), (1, bs, ie)).reshape(1, be)
-    keep_tiled = jnp.broadcast_to(keep.reshape(1, bs, 1), (1, bs, ie)).reshape(1, be)
-    # Delta merge: the written item wins at `pos`, kept slots copy the
-    # source, everything else is zero-filled (the delta-COW invariant).
-    out_ref[...] = jnp.where(
-        item_of_lane == pos,
-        val_tiled,
-        jnp.where(keep_tiled != 0, block, jnp.zeros_like(block)),
+    _merge(pos_ref, data_ref, val_ref, keep_ref, out_ref)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 7))
+def _call(kernel, data, src, dst, pos, values, keep, interpret):
+    n = src.shape[0]
+    splits, block = row_blocking(data.shape[1:])
+    item_block = (1, 1) + tuple(data.shape[2:])
+    tail = (0,) * (len(block) - 2)
+
+    def row_of(ref):
+        return lambda i, r, src_ref, dst_ref, pos_ref: (ref(src_ref, dst_ref)[i], r) + tail
+
+    in_specs = [
+        pl.BlockSpec(block, row_of(lambda s, d: s)),
+        pl.BlockSpec(item_block, lambda i, r, *refs: (i, 0) + tail),
+    ]
+    args = [src, dst, pos, data, values.reshape((n, 1) + data.shape[2:])]
+    if keep is not None:
+        # [n, bs] -> [n, bs, 1, ...]: a slot mask that broadcasts over items.
+        in_specs.append(
+            pl.BlockSpec(block[:2] + (1,) * len(tail), lambda i, r, *refs: (i, r) + tail)
+        )
+        args.append(keep.astype(jnp.int32).reshape((n, keep.shape[1]) + (1,) * len(tail)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(n, splits),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec(block, row_of(lambda s, d: d)),
     )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(data.shape, data.dtype),
+        input_output_aliases={3: 0},  # flat operand 3 = `data` (after 3 prefetch args)
+        interpret=interpret,
+    )(*args)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def cow_write_delta_pallas(
-    data: jax.Array,  # [num_blocks + 1, block_elems]; trailing dump row
+    data: jax.Array,  # [num_blocks + 1, bs, *item] (rank >= 3); trailing dump row
     src: jax.Array,  # [n] int32 — block to stream (dump for skipped rows)
     dst: jax.Array,  # [n] int32 — block to emit (dump for skipped rows)
     pos: jax.Array,  # [n] int32 — item offset within the block
-    values: jax.Array,  # [n, item_elems]
-    keep: jax.Array,  # [n, block_size] int32 — slots copied from src
+    values: jax.Array,  # [n, *item]
+    keep: jax.Array,  # [n, bs] — slots copied from src
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    n = src.shape[0]
-    block_elems = data.shape[1]
-    item_elems = values.shape[1]
-    block_size = keep.shape[1]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec(
-                (1, block_elems),
-                lambda i, src_ref, dst_ref, pos_ref: (src_ref[i], 0),
-            ),
-            pl.BlockSpec(
-                (1, item_elems),
-                lambda i, src_ref, dst_ref, pos_ref: (i, 0),
-            ),
-            pl.BlockSpec(
-                (1, block_size),
-                lambda i, src_ref, dst_ref, pos_ref: (i, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, block_elems),
-            lambda i, src_ref, dst_ref, pos_ref: (dst_ref[i], 0),
-        ),
-    )
-    return pl.pallas_call(
-        _kernel_delta,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(data.shape, data.dtype),
-        input_output_aliases={3: 0},  # flat operand 3 = `data` (after 3 prefetch args)
-        interpret=interpret,
-    )(src, dst, pos, data, values, keep)
+    return _call(_kernel_delta, data, src, dst, pos, values, keep, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def cow_write_pallas(
-    data: jax.Array,  # [num_blocks + 1, block_elems]; trailing dump row
+    data: jax.Array,  # [num_blocks + 1, bs, *item] (rank >= 3); trailing dump row
     src: jax.Array,  # [n] int32 — block to stream (dump for skipped rows)
     dst: jax.Array,  # [n] int32 — block to emit (dump for skipped rows)
     pos: jax.Array,  # [n] int32 — item offset within the block
-    values: jax.Array,  # [n, item_elems]
+    values: jax.Array,  # [n, *item]
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    n = src.shape[0]
-    block_elems = data.shape[1]
-    item_elems = values.shape[1]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec(
-                (1, block_elems),
-                lambda i, src_ref, dst_ref, pos_ref: (src_ref[i], 0),
-            ),
-            pl.BlockSpec(
-                (1, item_elems),
-                lambda i, src_ref, dst_ref, pos_ref: (i, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, block_elems),
-            lambda i, src_ref, dst_ref, pos_ref: (dst_ref[i], 0),
-        ),
-    )
-    return pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(data.shape, data.dtype),
-        input_output_aliases={3: 0},  # flat operand 3 = `data` (after 3 prefetch args)
-        interpret=interpret,
-    )(src, dst, pos, data, values)
+    return _call(_kernel, data, src, dst, pos, values, None, interpret)

@@ -10,11 +10,11 @@ every non-dump row (the dump row's content is unspecified — see
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.cow_write.kernel import cow_write_delta_pallas, cow_write_pallas
 from repro.kernels.cow_write.ref import cow_write_delta_ref, cow_write_ref
 from repro.kernels.dispatch import resolve_kernel_mode
+from repro.kernels.pool_rows import as_rank3
 
 
 def cow_write(
@@ -41,26 +41,21 @@ def cow_write(
     byte the pre-delta kernel invocation.
     """
     use_kernel, interpret = resolve_kernel_mode(use_kernel, interpret)
-    if keep is None:
-        if not use_kernel:
+    if not use_kernel:
+        if keep is None:
             out = cow_write_ref(data, src, dst, pos, values)
         else:
-            shape = data.shape
-            flat = data.reshape(shape[0], -1)
-            vals = values.reshape(values.shape[0], -1).astype(data.dtype)
-            out = cow_write_pallas(flat, src, dst, pos, vals, interpret=interpret)
-            out = out.reshape(shape)
-    elif not use_kernel:
-        out = cow_write_delta_ref(data, src, dst, pos, values, keep)
+            out = cow_write_delta_ref(data, src, dst, pos, values, keep)
     else:
-        shape = data.shape
-        flat = data.reshape(shape[0], -1)
-        vals = values.reshape(values.shape[0], -1).astype(data.dtype)
-        out = cow_write_delta_pallas(
-            flat, src, dst, pos, vals, keep.astype(jnp.int32),
-            interpret=interpret,
-        )
-        out = out.reshape(shape)
+        rows = as_rank3(data)
+        vals = values.astype(data.dtype)
+        if keep is None:
+            out = cow_write_pallas(rows, src, dst, pos, vals, interpret=interpret)
+        else:
+            out = cow_write_delta_pallas(
+                rows, src, dst, pos, vals, keep, interpret=interpret
+            )
+        out = out.reshape(data.shape)
     # Skipped rows self-copied the dump row in whatever order the backend
     # chose; re-zero it so pools compare leaf-for-leaf across paths.
     return out.at[out.shape[0] - 1].set(0)

@@ -43,7 +43,7 @@ def paged_attention(
     if use_kernel:
         return paged_attention_delta_pallas(
             q, k_pool, v_pool, tables, lengths,
-            parent, dirty.astype(jnp.int32), interpret=interpret,
+            parent, dirty, interpret=interpret,
         )
     return paged_attention_ref(
         q, k_pool, v_pool, tables, lengths, parent=parent, dirty=dirty

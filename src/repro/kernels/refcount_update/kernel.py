@@ -8,12 +8,15 @@ the pool (``add_refs``, ``sub_refs``, ``freeze``); here both the signed
 histogram and the membership mask accumulate in VMEM in a single pass
 over the flattened tables (DESIGN.md §3).
 
-Grid: one step per table chunk.  Each step one-hot-expands its chunk of
-new/old entries against the block-id lane (``[chunk, nb]`` compares on
-the VPU — compute-cheap, and the tables are read exactly once from HBM)
-and accumulates into the ``[1, nb]`` delta / membership outputs, whose
-index map pins them to a single revisited block.  NULL (-1) entries
-match no block id and drop out for free.
+Grid: (block-id tile, table chunk).  The tables arrive lane-dense as
+``[rows, 128]``; each step compares its rows against a tile of ``TN``
+block ids held on sublanes (``[TN, 128]`` compares on the VPU — both
+operands only broadcast, no relayout) and accumulates per-(id, lane)
+counts in VMEM scratch.  At the last chunk one transpose folds the lanes
+into the ``[1, TN]`` delta / membership outputs of that tile.  Tiling
+the ids bounds VMEM at any pool size (a one-hot over the whole pool is
+``[entries, num_blocks]``).  NULL (-1) entries and padding match no
+block id and drop out for free.
 """
 
 from __future__ import annotations
@@ -23,27 +26,38 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-_CHUNK = 256
+_LANES = 128
+_ROWS = 32  # table rows of 128 entries per grid step
+_TILE = 512  # block ids per tile
 
 
-def _kernel(new_ref, old_ref, delta_ref, member_ref):
-    i = pl.program_id(0)
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
-    @pl.when(i == 0)
+
+def _kernel(new_ref, old_ref, delta_ref, member_ref, dacc_ref, macc_ref):
+    t = pl.program_id(0)
+    s = pl.program_id(1)
+
+    @pl.when(s == 0)
     def _init():
-        delta_ref[...] = jnp.zeros_like(delta_ref)
-        member_ref[...] = jnp.zeros_like(member_ref)
+        dacc_ref[...] = jnp.zeros_like(dacc_ref)
+        macc_ref[...] = jnp.zeros_like(macc_ref)
 
-    nb = delta_ref.shape[1]
-    chunk = new_ref.shape[1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (chunk, nb), 1)
-    new_hits = new_ref[...].reshape(chunk, 1) == lane  # [chunk, nb]
-    old_hits = old_ref[...].reshape(chunk, 1) == lane
-    delta_ref[...] += (
-        new_hits.astype(jnp.int32) - old_hits.astype(jnp.int32)
-    ).sum(axis=0, keepdims=True)
-    member_ref[...] |= new_hits.any(axis=0, keepdims=True)
+    tn = dacc_ref.shape[0]
+    ids = t * tn + jax.lax.broadcasted_iota(jnp.int32, (tn, 1), 0)
+    for r in range(new_ref.shape[0]):
+        new_hits = (new_ref[r : r + 1, :] == ids).astype(jnp.int32)  # [tn, 128]
+        old_hits = (old_ref[r : r + 1, :] == ids).astype(jnp.int32)
+        dacc_ref[...] += new_hits - old_hits
+        macc_ref[...] = jnp.maximum(macc_ref[...], new_hits)
+
+    @pl.when(s == pl.num_programs(1) - 1)
+    def _fold():
+        delta_ref[...] = jnp.sum(dacc_ref[...].T, axis=0, keepdims=True)
+        member_ref[...] = jnp.max(macc_ref[...].T, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("num_blocks", "interpret"))
@@ -56,26 +70,33 @@ def refcount_delta_pallas(
 ):
     """Returns ``(delta [num_blocks] int32, member [num_blocks] bool)``."""
     e = new_tables.shape[0]
-    chunk = min(_CHUNK, max(e, 1))
-    pad = (-e) % chunk
-    new_p = jnp.pad(new_tables, (0, pad), constant_values=-1).reshape(-1, chunk)
-    old_p = jnp.pad(old_tables, (0, pad), constant_values=-1).reshape(-1, chunk)
-    steps = new_p.shape[0]
+    rows = min(_ROWS, _round_up(-(-max(e, 1) // _LANES), 8))
+    e_pad = _round_up(max(e, 1), rows * _LANES)
+    tn = min(_TILE, _round_up(num_blocks, _LANES))
+    nb_pad = _round_up(num_blocks, tn)
+
+    def lane_dense(x):
+        x = jnp.pad(x.astype(jnp.int32), (0, e_pad - e), constant_values=-1)
+        return x.reshape(-1, _LANES)
+
+    chunk = pl.BlockSpec((rows, _LANES), lambda t, s: (s, 0))
+    tile = pl.BlockSpec((1, tn), lambda t, s: (0, t))
     delta, member = pl.pallas_call(
         _kernel,
-        grid=(steps,),
-        in_specs=[
-            pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-            pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, num_blocks), lambda i: (0, 0)),
-            pl.BlockSpec((1, num_blocks), lambda i: (0, 0)),
-        ],
+        grid=(nb_pad // tn, e_pad // (rows * _LANES)),
+        in_specs=[chunk, chunk],
+        out_specs=[tile, tile],
         out_shape=[
-            jax.ShapeDtypeStruct((1, num_blocks), jnp.int32),
-            jax.ShapeDtypeStruct((1, num_blocks), jnp.bool_),
+            jax.ShapeDtypeStruct((1, nb_pad), jnp.int32),
+            jax.ShapeDtypeStruct((1, nb_pad), jnp.int32),
         ],
+        scratch_shapes=[
+            pltpu.VMEM((tn, _LANES), jnp.int32),
+            pltpu.VMEM((tn, _LANES), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(new_p, old_p)
-    return delta[0], member[0]
+    )(lane_dense(new_tables), lane_dense(old_tables))
+    return delta[0, :num_blocks], member[0, :num_blocks] != 0

@@ -23,6 +23,9 @@ def main() -> None:
     ap.add_argument("--particles", type=int, default=16)
     args = ap.parse_args()
 
+    from repro import compile_cache
+
+    compile_cache.enable()
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -154,7 +154,7 @@ def _attn_block(
     cache = kvc.write_kv(
         ccfg, cache, bid, pos, layer, k_new[:, 0], v_new[:, 0], mask
     )
-    k_pool, v_pool = kvc.layer_views(cache, layer)
+    k_pool, v_pool = kvc.layer_views(ccfg, cache, layer)
     # COW-native decode: under delta COW the attention gather resolves
     # delta pages through parent/dirty in place — no materialize pass.
     delta = dict(
@@ -272,20 +272,15 @@ def _prefill(
     for j in range(nb):
         pool, bids = pool_lib.alloc(pool, b)
         tables = tables.at[seq_ids, j].set(bids)
-    # [L, B, S, KVH, hd] -> pad, reshape into pages [B, nb, bs, ...]
+    # [L, B, S, KVH, hd] -> pad, reshape into pages [B * nb, L, bs, KVH * hd]
     def pages(arr):
         arr = jnp.pad(arr, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
         L = arr.shape[0]
-        return arr.reshape(L, b, nb, bs, cfg.n_kv_heads, cfg.hd)
+        arr = arr.reshape(L, b, nb, bs, cfg.n_kv_heads * cfg.hd)
+        return arr.transpose(1, 2, 0, 3, 4).reshape(b * nb, L, bs, -1)
 
     kp, vp = pages(k_all), pages(v_all)
     page_bids = tables[seq_ids, :nb].reshape(-1)  # [b*nb]
-    kp = kp.transpose(1, 2, 0, 3, 4, 5).reshape(
-        b * nb, kp.shape[0], bs, cfg.n_kv_heads, cfg.hd
-    )
-    vp = vp.transpose(1, 2, 0, 3, 4, 5).reshape(
-        b * nb, vp.shape[0], bs, cfg.n_kv_heads, cfg.hd
-    )
     data = pool.data.at[page_bids, :, 0].set(kp.astype(pool.data.dtype))
     data = data.at[page_bids, :, 1].set(vp.astype(pool.data.dtype))
     pool = pool._replace(data=data)

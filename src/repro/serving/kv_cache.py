@@ -4,8 +4,11 @@ This is the paper's platform applied to serving: sequences are the
 particles, tokens are the generations, and the KV cache is the payload.
 
   * a **block** holds ``block_size`` token positions across *all* layers
-    (pool payload ``[L, 2, bs, KVH, hd]``), so one refcount governs one
-    page of context;
+    (pool payload ``[L, 2, bs, KVH * hd]``), so one refcount governs one
+    page of context.  Heads and head dim share the minor axis: with
+    ``hd < 128`` a separate ``hd`` axis would be padded to the TPU's 128
+    lanes, and the device's default layout would then move the block
+    axis minor — every kernel over the pool would pay a full relayout;
   * ``fork`` (the resampling clone of population-based decoding, or the
     n-best fan-out of parallel sampling) is a table gather + refcount
     delta — **O(1) data movement** per sequence, Algorithm 3;
@@ -49,7 +52,7 @@ class KVCacheConfig:
     dtype: str = "float32"
     # Sub-block delta COW (DESIGN.md §3.2): a mid-page fork's COW copy
     # moves only the token slots the tail block has materialized (plus
-    # bookkeeping) instead of the whole ``[L, 2, bs, KVH, hd]`` page;
+    # bookkeeping) instead of the whole ``[L, 2, bs, KVH * hd]`` page;
     # the untouched prefix resolves through the parent page.  Paged
     # attention reads through ``pool.parent``/``pool.dirty`` directly
     # (COW-native decode), so no materialization is ever needed.  Off by
@@ -76,7 +79,7 @@ class KVCacheConfig:
 
 
 class PagedKVCache(NamedTuple):
-    pool: BlockPool  # data [num_blocks + 1, L, 2, bs, KVH, hd] (dump row last)
+    pool: BlockPool  # data [num_blocks + 1, L, 2, bs, KVH * hd] (dump row last)
     tables: jax.Array  # [max_seqs, max_blocks_per_seq] int32
     lengths: jax.Array  # [max_seqs] int32
 
@@ -84,7 +87,7 @@ class PagedKVCache(NamedTuple):
 def create(cfg: KVCacheConfig) -> PagedKVCache:
     pool = pool_lib.init(
         cfg.pool_blocks,
-        (cfg.n_layers, 2, cfg.block_size, cfg.n_kv_heads, cfg.head_dim),
+        (cfg.n_layers, 2, cfg.block_size, cfg.n_kv_heads * cfg.head_dim),
         jnp.dtype(cfg.dtype),
         npos=cfg.block_size,  # dirty mask tracks the token-position axis
     )
@@ -150,9 +153,7 @@ def ensure_writable(
         # with nothing to keep read the dump row (a zero page) instead
         # of the shared payload.
         src = jnp.where(need_copy & jnp.any(dirty_cur, axis=1), cur, pool.num_blocks)
-        payload = jnp.where(
-            dirty_cur[:, None, None, :, None, None], pool.data[src], 0
-        )
+        payload = jnp.where(dirty_cur[:, None, None, :, None], pool.data[src], 0)
         pool = pool_lib.write_blocks(pool, new_bid, payload, mask=need_copy)
     else:
         # Rows that don't COW read the dump row instead of materializing a
@@ -200,11 +201,12 @@ def write_kv(
     mask: jax.Array,
 ) -> PagedKVCache:
     sid = jnp.where(mask & (bid >= 0), bid, cache.pool.num_blocks)
+    dt = cache.pool.data.dtype
     data = cache.pool.data.at[sid, layer, 0, pos].set(
-        k.astype(cache.pool.data.dtype), mode="drop"
+        k.reshape(k.shape[0], -1).astype(dt), mode="drop"
     )
     data = data.at[sid, layer, 1, pos].set(
-        v.astype(cache.pool.data.dtype), mode="drop"
+        v.reshape(v.shape[0], -1).astype(dt), mode="drop"
     )
     # Masked rows landed in the dump row; re-zero its touched layer so
     # the kept-zero dump-row contract (repro.core.pool) holds here too.
@@ -216,10 +218,14 @@ def advance(cache: PagedKVCache, mask: jax.Array) -> PagedKVCache:
     return cache._replace(lengths=cache.lengths + jnp.where(mask, 1, 0))
 
 
-def layer_views(cache: PagedKVCache, layer) -> Tuple[jax.Array, jax.Array]:
+def layer_views(
+    cfg: KVCacheConfig, cache: PagedKVCache, layer
+) -> Tuple[jax.Array, jax.Array]:
     """(k_pool, v_pool) as [num_blocks + 1, bs, KVH, hd] for paged
     attention (the trailing dump row is unreachable through any table)."""
-    return cache.pool.data[:, layer, 0], cache.pool.data[:, layer, 1]
+    data = cache.pool.data
+    shape = (data.shape[0], cfg.block_size, cfg.n_kv_heads, cfg.head_dim)
+    return data[:, layer, 0].reshape(shape), data[:, layer, 1].reshape(shape)
 
 
 def used_blocks(cache: PagedKVCache) -> jax.Array:

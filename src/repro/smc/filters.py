@@ -33,7 +33,7 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import store as store_lib
@@ -518,7 +518,7 @@ class ParticleFilter:
                 mesh=mesh,
                 in_specs=(P(), P()),
                 out_specs=(P(), ax, sp),
-                check_rep=False,
+                check_vma=False,
             )
 
         init_fn = self._exec.jit_chunk("sharded_init", build_init)
@@ -554,7 +554,7 @@ class ParticleFilter:
                 mesh=mesh,
                 in_specs=(P(), ax, sp, ax, P(), P(), P(), P()) + (P(),) * n_extras,
                 out_specs=(P(), ax, sp, ax, P(), P(), P(), P()),
-                check_rep=False,
+                check_vma=False,
             )
 
         chunk = self._exec.jit_chunk(

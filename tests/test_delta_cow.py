@@ -168,7 +168,7 @@ class TestDeltaStoreObservational:
 
 
 def _effective_kv(cache, delta: bool) -> np.ndarray:
-    """Per-sequence effective payload: ``[S, mb, L, 2, bs, KVH, hd]``
+    """Per-sequence effective payload: ``[S, mb, L, 2, bs, KVH * hd]``
     with NULL blocks zeroed — delta pages resolved through parent."""
     pool = cache.pool
     tab = np.asarray(cache.tables)
@@ -177,7 +177,7 @@ def _effective_kv(cache, delta: bool) -> np.ndarray:
     if delta:
         par = np.asarray(pool.parent)[safe]
         res = np.where(par >= 0, par, safe)
-        sel = np.asarray(pool.dirty)[safe][:, :, None, None, :, None, None]
+        sel = np.asarray(pool.dirty)[safe][:, :, None, None, :, None]
         data = np.where(sel, data, np.asarray(pool.data)[res])
     data[tab < 0] = 0
     # Zero positions at or past each sequence's length.
@@ -185,7 +185,7 @@ def _effective_kv(cache, delta: bool) -> np.ndarray:
     bs = data.shape[4]
     pos = (np.arange(mb * bs).reshape(mb, bs))[None]  # [1, mb, bs]
     ok = pos < np.asarray(cache.lengths)[:, None, None]
-    data = np.where(ok[:, :, None, None, :, None, None], data, 0)
+    data = np.where(ok[:, :, None, None, :, None], data, 0)
     return data
 
 
@@ -284,7 +284,7 @@ class TestKVCacheDelta:
         # And the payload is what the whole-block path would hold:
         # tokens 1, 2 from the shared prefix, 9 at the boundary row.
         eff = _effective_kv(cache, delta=True)
-        got = eff[0, 0, 0, 0, :3, 0, 0]  # seq 0, block 0, layer 0, K
+        got = eff[0, 0, 0, 0, :3, 0]  # seq 0, block 0, layer 0, K, head 0 dim 0
         np.testing.assert_array_equal(got, np.asarray([1.0, 2.0, 9.0]))
 
     def test_free_cascade_reclaims_everything(self):

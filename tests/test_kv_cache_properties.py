@@ -96,7 +96,8 @@ def run_program(ops):
 
 def check_equiv(cache, dense, lengths):
     tables = np.asarray(cache.tables)
-    data = np.asarray(cache.pool.data)  # [nb, L, 2, BS, KVH, HD]
+    data = np.asarray(cache.pool.data)  # [nb, L, 2, BS, KVH * HD]
+    data = data.reshape(data.shape[:4] + (KVH, HD))
     for s in range(N_SEQS):
         for t in range(int(lengths[s])):
             blk = tables[s, t // BS]
