@@ -207,11 +207,12 @@ def _push_free_ids(
     stack: jax.Array, top: jax.Array, ids: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
     """Push non-NULL ids (must be distinct, and absent from the stack)."""
-    valid = ids >= 0
-    rank = jnp.cumsum(valid.astype(jnp.int32)) - 1
-    pos = jnp.where(valid, top + rank, stack.shape[0])
-    stack = stack.at[pos].set(ids, mode="drop")
-    return stack, top + jnp.sum(valid, dtype=jnp.int32)
+    with jax.named_scope("pool.free_push"):
+        valid = ids >= 0
+        rank = jnp.cumsum(valid.astype(jnp.int32)) - 1
+        pos = jnp.where(valid, top + rank, stack.shape[0])
+        stack = stack.at[pos].set(ids, mode="drop")
+        return stack, top + jnp.sum(valid, dtype=jnp.int32)
 
 
 def push_free_mask(
@@ -225,11 +226,12 @@ def push_free_mask(
     ascending order; the caller guarantees none is already in the stack.
     """
     nb = stack.shape[0]
-    ids = jnp.arange(nb, dtype=jnp.int32)
-    rank = jnp.cumsum(freed.astype(jnp.int32)) - 1
-    pos = jnp.where(freed, top + rank, nb)
-    stack = stack.at[pos].set(ids, mode="drop")
-    return stack, top + jnp.sum(freed, dtype=jnp.int32)
+    with jax.named_scope("pool.free_push"):
+        ids = jnp.arange(nb, dtype=jnp.int32)
+        rank = jnp.cumsum(freed.astype(jnp.int32)) - 1
+        pos = jnp.where(freed, top + rank, nb)
+        stack = stack.at[pos].set(ids, mode="drop")
+        return stack, top + jnp.sum(freed, dtype=jnp.int32)
 
 
 def alloc(
@@ -251,43 +253,44 @@ def alloc(
     Cost: O(n) gathers/scatters — no pass over the pool.  The legacy
     free-scan survives as :func:`alloc_scan`.
     """
-    if commit is None:
-        commit = jnp.ones((n,), dtype=jnp.bool_)
-    nb = pool.num_blocks
-    top = pool.free_top
-    i = jnp.arange(n, dtype=jnp.int32)
-    have = i < top
-    cand_pos = jnp.clip(top - 1 - i, 0, max(nb - 1, 0))
-    cand = jnp.where(have, pool.free_stack[cand_pos], NULL_BLOCK)
-    ok = have & commit
-    sids = _scatter_ids(nb, cand, ok)
-    refcount = pool.refcount.at[sids].add(1, mode="drop")
-    frozen = pool.frozen.at[sids].set(False, mode="drop")
-    parent = pool.parent.at[sids].set(NULL_BLOCK, mode="drop")
-    dirty = pool.dirty.at[sids].set(False, mode="drop")
-    oom = pool.oom | jnp.any(commit & ~have)
-    # Remove the committed candidates from the stack window, compacting
-    # the uncommitted survivors downward in their original relative
-    # order — an alloc whose commits all fail is a bit-exact no-op, which
-    # the sharded store's fixed-shape exchange relies on (its all-local
-    # steps still trace an alloc_compact of zero blocks).
-    keep = have & ~commit
-    kept = jnp.cumsum(keep.astype(jnp.int32))
-    base = top - jnp.sum(have, dtype=jnp.int32)
-    tgt = jnp.where(keep, base + (kept[-1] - kept), nb)
-    stack = pool.free_stack.at[tgt].set(cand, mode="drop")
-    top = top - jnp.sum(ok, dtype=jnp.int32)
-    out_ids = jnp.where(ok, cand, NULL_BLOCK)
-    pool = pool._replace(
-        refcount=refcount,
-        frozen=frozen,
-        oom=oom,
-        free_stack=stack,
-        free_top=top,
-        parent=parent,
-        dirty=dirty,
-    )
-    return pool, out_ids
+    with jax.named_scope("pool.alloc"):
+        if commit is None:
+            commit = jnp.ones((n,), dtype=jnp.bool_)
+        nb = pool.num_blocks
+        top = pool.free_top
+        i = jnp.arange(n, dtype=jnp.int32)
+        have = i < top
+        cand_pos = jnp.clip(top - 1 - i, 0, max(nb - 1, 0))
+        cand = jnp.where(have, pool.free_stack[cand_pos], NULL_BLOCK)
+        ok = have & commit
+        sids = _scatter_ids(nb, cand, ok)
+        refcount = pool.refcount.at[sids].add(1, mode="drop")
+        frozen = pool.frozen.at[sids].set(False, mode="drop")
+        parent = pool.parent.at[sids].set(NULL_BLOCK, mode="drop")
+        dirty = pool.dirty.at[sids].set(False, mode="drop")
+        oom = pool.oom | jnp.any(commit & ~have)
+        # Remove the committed candidates from the stack window, compacting
+        # the uncommitted survivors downward in their original relative
+        # order — an alloc whose commits all fail is a bit-exact no-op, which
+        # the sharded store's fixed-shape exchange relies on (its all-local
+        # steps still trace an alloc_compact of zero blocks).
+        keep = have & ~commit
+        kept = jnp.cumsum(keep.astype(jnp.int32))
+        base = top - jnp.sum(have, dtype=jnp.int32)
+        tgt = jnp.where(keep, base + (kept[-1] - kept), nb)
+        stack = pool.free_stack.at[tgt].set(cand, mode="drop")
+        top = top - jnp.sum(ok, dtype=jnp.int32)
+        out_ids = jnp.where(ok, cand, NULL_BLOCK)
+        pool = pool._replace(
+            refcount=refcount,
+            frozen=frozen,
+            oom=oom,
+            free_stack=stack,
+            free_top=top,
+            parent=parent,
+            dirty=dirty,
+        )
+        return pool, out_ids
 
 
 def alloc_scan(

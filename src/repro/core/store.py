@@ -79,7 +79,6 @@ __all__ = [
     "materialize_batch",
     "import_trajectories",
     "used_blocks",
-    "used_bytes",
     "oom_flag",
     "free_blocks",
     "grow",
@@ -177,9 +176,10 @@ def create(cfg: StoreConfig) -> ParticleStore:
 
 
 def _bump_peak(cfg: StoreConfig, store: ParticleStore) -> ParticleStore:
-    return store._replace(
-        peak_blocks=jnp.maximum(store.peak_blocks, used_blocks(cfg, store))
-    )
+    with jax.named_scope("store.count"):
+        return store._replace(
+            peak_blocks=jnp.maximum(store.peak_blocks, used_blocks(cfg, store))
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -194,7 +194,8 @@ def append(cfg: StoreConfig, store: ParticleStore, values: jax.Array) -> Particl
     place are copied first (copy-on-write); fresh blocks are allocated at
     block boundaries.
     """
-    store = _write_impl(cfg, store, store.lengths, values, advance=True)
+    with jax.named_scope("store.append"):
+        store = _write_impl(cfg, store, store.lengths, values, advance=True)
     return _bump_peak(cfg, store)
 
 
@@ -212,7 +213,8 @@ def write_at(
     """
     if mask is None:
         mask = jnp.ones((cfg.n,), dtype=jnp.bool_)
-    store = _write_impl(cfg, store, positions, values, advance=False, mask=mask)
+    with jax.named_scope("store.append"):
+        store = _write_impl(cfg, store, positions, values, advance=False, mask=mask)
     return _bump_peak(cfg, store)
 
 
@@ -263,9 +265,10 @@ def _write_impl(
     pool, new_bid = pool_lib.alloc(pool, n, commit=need_block)
     # Transient peak: COW sources and their copies coexist until the
     # writer's reference is released below (a real allocator pays this).
-    store = store._replace(
-        peak_blocks=jnp.maximum(store.peak_blocks, pool_lib.blocks_in_use(pool))
-    )
+    with jax.named_scope("store.count"):
+        store = store._replace(
+            peak_blocks=jnp.maximum(store.peak_blocks, pool_lib.blocks_in_use(pool))
+        )
     if cfg.delta_cow:
         # The child's reference on its parent — added *before* the
         # writer's reference on cur is released, so a parent shared only
@@ -352,14 +355,15 @@ def _clone_bookkeeping(
     ``old_tables`` (always true for resampling ancestors), so no block
     is resurrected behind the stack's back.
     """
-    refcount, frozen, freed = refcount_update(
-        pool.refcount,
-        pool.frozen,
-        new_tables,
-        old_tables,
-        do_freeze=cfg.mode is CopyMode.LAZY,
-        use_kernel=cfg.use_kernels,
-    )
+    with jax.named_scope("store.refcount"):
+        refcount, frozen, freed = refcount_update(
+            pool.refcount,
+            pool.frozen,
+            new_tables,
+            old_tables,
+            do_freeze=cfg.mode is CopyMode.LAZY,
+            use_kernel=cfg.use_kernels,
+        )
     stack, top = pool_lib.push_free_mask(pool.free_stack, pool.free_top, freed)
     pool = pool._replace(
         refcount=refcount, frozen=frozen, free_stack=stack, free_top=top
@@ -368,7 +372,8 @@ def _clone_bookkeeping(
         # Freed delta children release their parent reference (the
         # mask-shaped cascade; a value-level no-op when nothing freed
         # was a delta block).
-        pool = pool_lib.release_parents(pool, freed)
+        with jax.named_scope("store.refcount"):
+            pool = pool_lib.release_parents(pool, freed)
     return pool
 
 
@@ -415,25 +420,29 @@ def clone_chain(
         ancestors = resampling.resample_systematic(key, logw)
         return clone(cfg, store, ancestors), ancestors
 
-    ancestors, new_tables, delta, member = clone_chain_op(
-        key,
-        logw,
-        store.tables,
-        num_blocks=store.pool.num_blocks,
-        use_kernel=cfg.use_kernels,
-    )
-    # The same bookkeeping _clone_bookkeeping applies, fed by the fused
-    # op's histogram instead of a second table pass.
-    pool = store.pool
-    refcount = pool.refcount + delta
-    freed = (pool.refcount > 0) & (refcount == 0)
-    frozen = pool.frozen | member if cfg.mode is CopyMode.LAZY else pool.frozen
+    # One scope for the whole fused op: its kernel path is a single
+    # pallas_call, so the comb and the table gather go with the histogram.
+    with jax.named_scope("store.refcount"):
+        ancestors, new_tables, delta, member = clone_chain_op(
+            key,
+            logw,
+            store.tables,
+            num_blocks=store.pool.num_blocks,
+            use_kernel=cfg.use_kernels,
+        )
+        # The same bookkeeping _clone_bookkeeping applies, fed by the fused
+        # op's histogram instead of a second table pass.
+        pool = store.pool
+        refcount = pool.refcount + delta
+        freed = (pool.refcount > 0) & (refcount == 0)
+        frozen = pool.frozen | member if cfg.mode is CopyMode.LAZY else pool.frozen
     stack, top = pool_lib.push_free_mask(pool.free_stack, pool.free_top, freed)
     pool = pool._replace(
         refcount=refcount, frozen=frozen, free_stack=stack, free_top=top
     )
     if cfg.delta_cow:
-        pool = pool_lib.release_parents(pool, freed)
+        with jax.named_scope("store.refcount"):
+            pool = pool_lib.release_parents(pool, freed)
     store = store._replace(
         pool=pool, tables=new_tables, lengths=store.lengths[ancestors]
     )
@@ -641,15 +650,6 @@ def used_blocks(cfg: StoreConfig, store: ParticleStore) -> jax.Array:
         per = (store.lengths + cfg.block_size - 1) // cfg.block_size
         return jnp.sum(per)
     return pool_lib.blocks_in_use(store.pool)
-
-
-def used_bytes(cfg: StoreConfig, store: ParticleStore) -> jax.Array:
-    item_bytes = jnp.dtype(cfg.dtype).itemsize
-    for d in cfg.item_shape:
-        item_bytes *= d
-    block_bytes = item_bytes * cfg.block_size
-    table_bytes = 4 * cfg.n * cfg.max_blocks if cfg.mode.is_lazy else 0
-    return used_blocks(cfg, store) * block_bytes + table_bytes
 
 
 def oom_flag(cfg: StoreConfig, store: ParticleStore) -> jax.Array:

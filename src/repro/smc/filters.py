@@ -354,6 +354,7 @@ class ParticleFilter:
             else:
                 do = (t > 0) & resampling.should_resample(logw, cfg.ess_threshold)
 
+            @jax.named_scope("filter.resample")
             def yes(operand):
                 key, state, store, logw = operand
                 lw = logw
@@ -434,10 +435,11 @@ class ParticleFilter:
             key, k_res, k_prop, k_alive = jax.random.split(key, 4)
             state, store, logw, did = maybe_resample(k_res, t, state, store, logw)
             prev_state = state
-            state, dlogw, record = propagate(k_prop, state, t, logw)
-            state, dlogw, record = alive_loop(
-                k_alive, state, t, logw, dlogw, record, prev_state
-            )
+            with jax.named_scope("filter.propagate"):
+                state, dlogw, record = propagate(k_prop, state, t, logw)
+                state, dlogw, record = alive_loop(
+                    k_alive, state, t, logw, dlogw, record, prev_state
+                )
             if csmc is not None:
                 # Pin particle 0 to the reference record.
                 reference, use_ref = csmc
@@ -453,11 +455,10 @@ class ParticleFilter:
             logz = logz + jax.scipy.special.logsumexp(lw)
             logw = resampling.normalize(lw)
             store = store_lib.append(scfg, store, record)
-            out = (
-                resampling.ess(logw),
-                did,
-                store_lib.used_blocks(scfg, store),
-            )
+            ess = resampling.ess(logw)
+            with jax.named_scope("store.count"):
+                used = store_lib.used_blocks(scfg, store)
+            out = (ess, did, used)
             return (key, state, store, logw, logz), out
 
         return scan_step
@@ -634,6 +635,7 @@ class ParticleFilter:
                 glogw = sharded_lib.gather_global(logw, axis)
                 do = (t > 0) & resampling.should_resample(glogw, cfg.ess_threshold)
 
+            @jax.named_scope("filter.resample")
             def yes(operand):
                 key, state, store, logw = operand
                 # Weights are globally normalized in the carry, so the
@@ -681,7 +683,8 @@ class ParticleFilter:
             state, store, logw, did = maybe_resample(
                 k_res, t, state, store, logw, s, lo
             )
-            state, dlogw, record = propagate(k_prop, state, t, logw, s)
+            with jax.named_scope("filter.propagate"):
+                state, dlogw, record = propagate(k_prop, state, t, logw, s)
             if csmc is not None:
                 # Pin local row 0 of shard 0 — global particle 0 — to
                 # the reference record.
@@ -701,11 +704,10 @@ class ParticleFilter:
             glw_norm = resampling.normalize(glw)
             logw = lax.dynamic_slice_in_dim(glw_norm, lo, nl)
             store = store_lib.append(local, store, record)
-            out = (
-                resampling.ess(glw_norm),
-                did,
-                lax.psum(store_lib.used_blocks(local, store), axis),
-            )
+            ess = resampling.ess(glw_norm)
+            with jax.named_scope("store.count"):
+                used = lax.psum(store_lib.used_blocks(local, store), axis)
+            out = (ess, did, used)
             return (key, state, store, logw, logz), out
 
         return scan_step, shard_key
