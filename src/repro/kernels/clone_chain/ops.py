@@ -10,6 +10,13 @@ refcount delta / freeze membership — everything
 The weight math replicates :func:`repro.smc.resampling.resample_systematic`
 verbatim (normalize -> exp -> cumsum with tail guard -> one scalar
 uniform), so fused and composed paths are ancestor-bit-exact.
+
+The two bodies compute the same delta differently.  The jnp path
+(:func:`clone_chain_ref`) scatters the old tables once, each entry
+weighted by its row's offspring count less one, and never passes the
+gathered tables through a histogram.  The Pallas path gathers in one
+kernel and takes the signed histogram ``+new -old`` in the tiled
+:mod:`repro.kernels.refcount_update` kernel.
 """
 
 from __future__ import annotations
